@@ -23,6 +23,9 @@ node fewer shares it), so the ratio sits near 1 and is a stable
 SAME-RUN ratio — host speed cancels. The kill-window spike is reported
 but NOT gated (its magnitude is one backoff schedule, not a trend).
 
+The daemons run on the CPU backend: on a host with a TPU the bench
+stops at once, since three daemons cannot share one chip.
+
 ``--json`` writes BENCH_cluster.json at the repo root (checked in per
 PR); ``--quick`` trims op counts but keeps every phase and the kill.
 """
@@ -37,6 +40,7 @@ REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO_ROOT / "tests"))  # the chaos harness
 
 from repro.core.cluster import ClusterClient  # noqa: E402
+from repro.launch.mesh import refuse_fleet_on_accelerator  # noqa: E402
 
 from _chaos import spawn_fleet  # noqa: E402
 
@@ -71,6 +75,7 @@ def run(quick: bool = False) -> dict:
     n_reads = N_READS_QUICK if quick else N_READS
     n_kill = N_KILL_OPS_QUICK if quick else N_KILL_OPS
     seed_rows = 200
+    refuse_fleet_on_accelerator("cluster bench")
     fleet = spawn_fleet(3)
     cc = None
     try:

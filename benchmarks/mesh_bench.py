@@ -19,11 +19,15 @@ policy). This bench answers the two questions that placement raises:
    cancel to first order, and a regression means the cross-device
    fan-out path itself got slower relative to single-device dispatch.
 
-Measurement runs in a SUBPROCESS with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8``: the parent
-process (benchmarks/run.py) has already initialized jax with however
-many devices the host exposes — typically one — and XLA device count
-is fixed at first use. The worker builds one mesh-placed and one
+On an accelerator host the measurement runs in this process over the
+devices the backend has (it needs at least two; 8 shards spread over
+them): a child process would contend for the chips this one holds.
+On the CPU backend (e.g. ``JAX_PLATFORMS=cpu``) it runs in a SUBPROCESS with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` unless this
+process already has 8 devices: the parent (benchmarks/run.py) has
+already initialized jax with however many devices the host exposes —
+typically one — and XLA device count is fixed at first use. The
+worker builds one mesh-placed and one
 unplaced ``SQLCached`` over IDENTICAL 8-shard schemas (fixed per-shard
 capacity, ~90% full, unique partition keys) and samples all four
 (placement, route) timers ROUND-ROBIN in a single loop — paired
@@ -101,9 +105,9 @@ def _build(shard_rows: int):
     from repro.core import shards as SH
     from repro.core.daemon import SQLCached
 
-    assert jax.device_count() == N_DEVICES, (
-        f"worker expected {N_DEVICES} forced host devices, got "
-        f"{jax.device_count()} — XLA_FLAGS not applied before jax init?")
+    assert jax.device_count() > 1, (
+        f"the mesh bench needs several devices, got {jax.device_count()}"
+        " — XLA_FLAGS not applied before jax init?")
     create = (f"CREATE TABLE mt (k INT, w INT) "
               f"CAPACITY {shard_rows * N_SHARDS} MAX_SELECT 8 "
               f"SHARDS {N_SHARDS} PARTITION BY k")
@@ -186,14 +190,25 @@ def worker(shard_rows: int, reps: int) -> dict:
 # ----------------------------------------------------------------- parent
 
 def run(quick: bool = False) -> dict:
-    """Spawn the forced-8-device worker subprocess and collect its JSON.
-
-    The current process's jax device topology is already fixed, so the
-    measurement CANNOT run in-process — XLA_FLAGS must be set before
-    the worker's first jax import.
-    """
+    """Measure in this process when the backend already has the devices,
+    else (CPU only) spawn the forced-8-device worker subprocess and
+    collect its JSON. The current process's jax device topology is
+    already fixed, so XLA_FLAGS must be set before the worker's first
+    jax import."""
+    import jax
+    shard_rows = QUICK_SHARD_ROWS if quick else SHARD_ROWS
+    reps = REPS_QUICK if quick else REPS
+    n, backend = jax.device_count(), jax.default_backend()
+    if n >= N_DEVICES or (n > 1 and backend != "cpu"):
+        return worker(shard_rows, reps)
+    if backend != "cpu":
+        raise RuntimeError(
+            f"mesh bench: the {backend} backend has one device; run it on "
+            "a multi-chip host, or under JAX_PLATFORMS=cpu (forced host "
+            "devices)")
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={N_DEVICES}"
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("REPRO_MESH", None)       # the worker builds both placements
     env["PYTHONPATH"] = (str(REPO_ROOT / "src")
                          + (os.pathsep + env["PYTHONPATH"]

@@ -9,10 +9,11 @@
          ``BENCH_cluster.json`` / ``BENCH_mesh.json`` /
          ``BENCH_serve.json`` / ``BENCH_obs.json`` to the repo root
          (ops/s resp. stmts/s, p50/p99 µs); these files are checked in
-         so every PR's numbers are comparable. The mesh bench measures
-         in a SUBPROCESS with ``XLA_FLAGS=--xla_force_host_platform_
-         device_count=8`` — this process's jax device topology is
-         already fixed at one device by the time benches import.
+         so every PR's numbers are comparable. On the CPU backend the
+         mesh bench measures in a SUBPROCESS with ``XLA_FLAGS=--xla_
+         force_host_platform_device_count=8`` — this process's jax
+         device topology is already fixed at one device by the time
+         benches import; on a multi-chip host it measures in-process.
 --quick  tier-1-friendly smoke sizes — finishes in seconds on CPU (the
          protocol bench keeps its 8-connection shape, fewer statements;
          the index bench keeps the 65536-row point --check compares).
@@ -207,6 +208,8 @@ def check() -> int:
 def main() -> None:
     quick = "--quick" in sys.argv
     as_json = "--json" in sys.argv
+    from repro.core.execache import use_persistent_cache
+    use_persistent_cache()
 
     if "--check" in sys.argv:
         failures = check()

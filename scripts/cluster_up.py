@@ -10,6 +10,9 @@ the foreground: Ctrl-C (or SIGTERM) tears the fleet down; killing one
 child by hand (``kill -9 <pid>``) is the supported way to poke failover
 while a client runs. Ports are OS-assigned by default so several
 clusters coexist; pass ``--ports 7001,7002,7003`` to pin them.
+
+A local fleet runs on the CPU backend only: on a host with a TPU the
+script exits at once, since every daemon would claim the one chip.
 """
 from __future__ import annotations
 
@@ -48,6 +51,9 @@ def main() -> None:
     ap.add_argument("--ports", default="",
                     help="comma-separated fixed ports (default: OS picks)")
     args = ap.parse_args()
+    sys.path.insert(0, os.path.join(REPO, "src"))
+    from repro.launch.mesh import refuse_fleet_on_accelerator
+    refuse_fleet_on_accelerator("cluster_up")
     ports = ([int(p) for p in args.ports.split(",")] if args.ports
              else [0] * args.n)
     if len(ports) != args.n:

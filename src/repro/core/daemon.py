@@ -235,7 +235,7 @@ from repro.core import shards as SH
 from repro.core import sqlparse as S
 from repro.core import table as T
 from repro.core import telemetry as TEL
-from repro.core.execache import ExecutorCache
+from repro.core.execache import ExecutorCache, use_persistent_cache
 from repro.core.schema import ExpiryPolicy, TableSchema, make_schema
 
 
@@ -540,6 +540,7 @@ class SQLCached:
     def __init__(self, auto_expire: bool = True, lane_exec: bool = True,
                  mesh_exec: bool = True, warmup: bool | None = None,
                  slow_ms: float | None = None):
+        use_persistent_cache()
         self.tables: dict[str, _Table] = {}
         self.interner = Interner()
         # serving telemetry (core/telemetry.py): trace spans, latency
@@ -1176,7 +1177,8 @@ class SQLCached:
         """CREATE-time background warm-up: pre-plan the canonical hot
         shapes off the dispatch thread. Best-effort by contract — a
         statement that raced a DROP/RESHARD just stops; warm-up must
-        never take down serving."""
+        never take down serving. A failure is recorded in the table's
+        ``SHOW STATS`` ``executors.warmup_errors``."""
         t = self.tables.get(name)
         if t is None:
             return
@@ -1186,7 +1188,9 @@ class SQLCached:
             try:
                 self.shape_key(sql)
                 self._warm_statement(t, self._parse(sql))
-            except Exception:  # noqa: BLE001 — warm-up is best effort
+            except Exception as e:  # noqa: BLE001 — warm-up is best effort
+                t.execs.warmup_errors.append(
+                    f"{sql}: {type(e).__name__}: {e}"[:2000])
                 return
 
     def drain_warmup(self, table: str | None = None) -> None:
@@ -2322,7 +2326,7 @@ class SQLCached:
                 else:
                     state, ns = run(update_plan)
                 for c in idx_rebuild:  # deferred: ONE rebuild per dispatch
-                    state = eng.build_index(xsch, state, c, mode="ref")
+                    state = eng.build_index(xsch, state, c)
                 # un-tick the padded scan iterations (runtime count — see
                 # the delete branch note on executor caching)
                 pad = b - jnp.sum(active.astype(jnp.int32))

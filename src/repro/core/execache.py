@@ -32,9 +32,15 @@ committed device) BEFORE executing, and a mismatch raises without
 consuming donated buffers — so :meth:`ExecEntry.__call__` can fall back
 to the lazy jitted callable with the caller's state intact. Fallbacks
 count as misses; correctness never depends on the AOT path.
+
+Across processes, :func:`use_persistent_cache` points JAX's persistent
+compilation cache at a fixed directory, so a restarted daemon loads the
+executables it warmed last time instead of compiling them again.
 """
 from __future__ import annotations
 
+import os
+import pathlib
 import threading
 import time
 from typing import Any, Callable
@@ -44,6 +50,25 @@ import jax.numpy as jnp
 
 from repro.core import telemetry as TEL
 from repro.lint import lockorder as LK
+
+# <checkout>/.jax_cache: a fixed path, because the directory is part of
+# what a later process must find again
+_DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory: ``JAX_COMPILATION_CACHE_DIR`` when it is set
+    (JAX reads it itself, and no other directory is set here), else
+    ``<checkout>/.jax_cache``. Every compile is cached, however short:
+    the daemon's executors are many small programs, and each one
+    recompiled on a cold start costs serving latency. Idempotent."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(_DEFAULT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path
 
 __all__ = ["ExecEntry", "ExecutorCache"]
 
@@ -153,6 +178,10 @@ class ExecutorCache:
         # mode, placement). Host-only; read by scheduler admission and
         # EXPLAIN. Cleared on bump() with the entries they describe.
         self.sigs: set = set()
+        # "<stmt>: <error>" for each CREATE-time background warm-up that
+        # failed (e.g. a compile the backend refused), so SHOW STATS
+        # shows it before the first live statement hits the same error
+        self.warmup_errors: list[str] = []
         self._lock = LK.make_lock("execache.entries")
         # Atomic counters: the concurrent wave path increments these from
         # several worker threads at once (see telemetry.Counters).
@@ -222,4 +251,5 @@ class ExecutorCache:
             "compiles": self.compiles,
             "fallbacks": self.fallbacks,
             "compile_ms_total": round(self.compile_ms_total, 3),
+            "warmup_errors": list(self.warmup_errors),
         }

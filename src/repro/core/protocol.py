@@ -486,12 +486,15 @@ class SQLCachedServer:
     async def stop(self) -> None:
         for srv in self._servers:
             srv.close()
-            await srv.wait_closed()
-        self._servers.clear()
+        # since Python 3.12.1 wait_closed() also waits for every live
+        # connection, so the handlers are cancelled before it is awaited
         for t in list(self._conn_tasks):
             t.cancel()
         if self._conn_tasks:
             await asyncio.gather(*self._conn_tasks, return_exceptions=True)
+        for srv in self._servers:
+            await srv.wait_closed()
+        self._servers.clear()
         await self.scheduler.stop()
 
     # ------------------------------------------------------------- protocol
@@ -1081,6 +1084,11 @@ class ThreadedServer:
             self._loop.run_forever()
 
     def stop(self) -> None:
+        """Close the listeners and connections, then end the loop thread.
+        A second call returns at once (the loop no longer runs)."""
+        if not self._thread.is_alive():
+            return
+
         async def down():
             await self.server.stop()
 
@@ -1125,4 +1133,6 @@ if __name__ == "__main__":
     ap.add_argument("--port", type=int, default=11222)
     ap.add_argument("--unix", default=None)
     a = ap.parse_args()
+    from repro.core.execache import use_persistent_cache
+    use_persistent_cache()
     run_server_forever(a.host, a.port, a.unix)

@@ -443,7 +443,6 @@ def insert(
     payloads: Mapping[str, jax.Array] | None = None,
     row_mask: jax.Array | None = None,
     ttl: jax.Array | int = 0,
-    index_mode: str | None = "ref",
 ):
     """Hash-routed batch insert: ONE device-side split + ONE vmapped
     per-shard insert. Returns (state, slots[n], evicted) — slots are
@@ -486,7 +485,7 @@ def insert(
             vals = {c: v[r_l] for c, v in vals_b.items()}
             pls = {k: v[r_l] for k, v in pls_b.items()}
             return T.insert(s_sch, st, vals, pls, m_l, ttl_b[r_l],
-                            index_mode=index_mode, alloc=alloc)
+                            alloc=alloc)
 
         return fn
 
@@ -928,14 +927,11 @@ def flush(schema: TableSchema, state: dict):
     return state, jnp.sum(ns)
 
 
-def build_index(schema: TableSchema, state: dict, column: str | None = None,
-                *, mode: str | None = None) -> dict:
-    """(Re)build hash indexes on every shard (vmapped — the jnp build
-    path IS the fused form under vmap, so the kernel mode is pinned)."""
+def build_index(schema: TableSchema, state: dict,
+                column: str | None = None) -> dict:
+    """(Re)build hash indexes on every shard (vmapped)."""
     s_sch = shard_schema(schema)
-    return _fanout(
-        lambda st: T.build_index(s_sch, st, column, mode=mode or "ref"),
-        state)
+    return _fanout(lambda st: T.build_index(s_sch, st, column), state)
 
 
 def reshard(old_schema: TableSchema, new_schema: TableSchema, lanes):
@@ -995,7 +991,7 @@ def reshard(old_schema: TableSchema, new_schema: TableSchema, lanes):
     for c in new_schema.indexes:
         nb = HX.n_buckets_for(cap_new)
         rid, key, ov = jax.vmap(
-            lambda kc, v: OPS.hash_build(kc, v, n_buckets=nb, mode="ref"))(
+            lambda kc, v: OPS.hash_build(kc, v, n_buckets=nb))(
                 n_cols[c], m)
         indexes[c] = {"rid": rid, "key": key, "stale": ov}
     stacked = {"cols": n_cols, "payloads": n_pls, "valid": m,

@@ -147,7 +147,6 @@ def insert(
     payloads: Mapping[str, jax.Array] | None = None,
     row_mask: jax.Array | None = None,
     ttl: jax.Array | int = 0,
-    index_mode: str | None = None,
     alloc: str | None = None,
 ):
     """Insert a batch of rows. ``values[col]`` has shape [n]; all columns
@@ -157,9 +156,7 @@ def insert(
     ``BULK_INDEX_THRESHOLD`` re-home all written slots in one batched
     clear + rank-place pass (``HX.insert_update_batched`` — no serial
     per-slot chain); wider batches take ONE bulk sort-based rebuild
-    instead. ``index_mode`` pins the bulk build's kernel
-    implementation (executors running under vmap pass ``"ref"``);
-    ``alloc`` pins the slot-allocator path (see ``_alloc_slots``).
+    instead. ``alloc`` pins the slot-allocator path (see ``_alloc_slots``).
 
     Returns (state, slots[n], evicted_count)."""
     payloads = payloads or {}
@@ -212,7 +209,7 @@ def insert(
             nb = HX.n_buckets_for(cap)
             for ixc in schema.indexes:
                 rid, key, overflow = OPS.hash_build(
-                    cols[ixc], valid, n_buckets=nb, mode=index_mode)
+                    cols[ixc], valid, n_buckets=nb)
                 upd[ixc] = {"rid": rid, "key": key, "stale": overflow}
         else:
             for ixc in schema.indexes:
@@ -345,8 +342,8 @@ def _route(schema, where, params, plan):
     return route, False
 
 
-def build_index(schema: TableSchema, state: dict, column: str | None = None,
-                *, mode=None) -> dict:
+def build_index(schema: TableSchema, state: dict,
+                column: str | None = None) -> dict:
     """(Re)build the hash index(es) from the current column/validity state
     — the bulk path behind CREATE-with-data, UPDATEs that rewrite an
     indexed column, and explicit recovery from a stale (overflowed)
@@ -356,7 +353,7 @@ def build_index(schema: TableSchema, state: dict, column: str | None = None,
     nb = HX.n_buckets_for(schema.capacity)
     for c in cols:
         rid, key, overflow = OPS.hash_build(
-            state["cols"][c], state["valid"], n_buckets=nb, mode=mode)
+            state["cols"][c], state["valid"], n_buckets=nb)
         indexes[c] = {"rid": rid, "key": key, "stale": overflow}
     return dict(state, indexes=indexes)
 
@@ -548,7 +545,7 @@ def update(
         written = {tgt for tgt, _ in set_items}
         for ixc in schema.indexes:
             if ixc in written:
-                state = build_index(schema, state, ixc, mode=probe_mode)
+                state = build_index(schema, state, ixc)
     state = _tick(state)
     return state, n
 

@@ -30,18 +30,19 @@ time and reclaimed when their slot is reused (the old key is still
 readable, exactly like kvpool's page-table trick). UPDATEs that write an
 indexed column rebuild that index in the same dispatch.
 
-Kernel pair (mode selection in ``kernels/ops.hash_build/hash_probe``):
+Build and probe (entry points ``kernels/ops.hash_build/hash_probe``):
 
-``build``   bulk (re)build: an XLA sort groups row ids by bucket, then a
-            grid-tiled kernel gathers each bucket's contiguous segment
-            into its ``[bucket_cap]`` lane row (pure gathers — no
-            cross-tile scatter conflicts).
-``probe``   batched lookup: bucket ids ride in as prefetched scalars so
-            the BlockSpec index map DMAs exactly one bucket tile per
-            query; the kernel emits candidate row ids + key-match bits.
-
-The jnp reference paths double as the fast mode on non-TPU backends
-(gather/sort shapes XLA already handles well).
+``build``   bulk (re)build, XLA only: one sort groups row ids by bucket,
+            then gathers pull each bucket's contiguous segment into its
+            ``[bucket_cap]`` lane row. The key gather is random access,
+            which a TPU kernel could only issue as one DMA per row, so
+            there is no Pallas variant.
+``probe``   batched lookup, a Pallas kernel (mode selection in ``ops``):
+            bucket ids ride in as prefetched scalars so the BlockSpec
+            index map DMAs the one aligned tile holding each query's
+            bucket; the kernel emits candidate row ids + key-match bits.
+            ``probe_ref`` is its jnp oracle and the fast mode on non-TPU
+            backends.
 """
 from __future__ import annotations
 
@@ -89,7 +90,7 @@ def empty_index(n_buckets: int, bucket_cap: int = BUCKET_CAP) -> dict:
 # ------------------------------------------------------------------- build
 
 def _build_sorted(keys: jax.Array, valid: jax.Array, n_buckets: int):
-    """Shared build prologue: group row ids by bucket with one XLA sort.
+    """Build prologue: group row ids by bucket with one XLA sort.
 
     Returns (order, sb, start, overflow): ``order`` is row ids sorted by
     bucket (invalid rows pushed to the end under sentinel ``n_buckets``),
@@ -110,8 +111,8 @@ def _build_sorted(keys: jax.Array, valid: jax.Array, n_buckets: int):
     return order, sb, start, overflow
 
 
-def build_ref(keys: jax.Array, valid: jax.Array, *, n_buckets: int):
-    """jnp oracle / fast path: gather each bucket's sorted segment.
+def build(keys: jax.Array, valid: jax.Array, *, n_buckets: int):
+    """Bulk (re)build: gather each bucket's sorted segment.
 
     Returns (rid [nb, cap_b], key [nb, cap_b], stale scalar)."""
     cap = keys.shape[0]
@@ -130,65 +131,6 @@ def build_ref(keys: jax.Array, valid: jax.Array, *, n_buckets: int):
     return rid, key, overflow
 
 
-def _build_kernel(start_ref, order_ref, sb_ref, keys_ref, rid_ref, key_ref,
-                  *, tb: int, cap_pad: int):
-    """One grid step fills ``tb`` bucket rows: per bucket, one dynamic
-    slice pulls its contiguous sorted segment (pure gather — buckets never
-    collide across tiles, so no scatter hazards)."""
-    i = pl.program_id(0)
-    for t in range(tb):  # static unroll: tb is small (8 sublanes)
-        b = i * tb + t
-        s = start_ref[t]
-        seg = order_ref[pl.ds(s, BUCKET_CAP)]          # [cap_b] row ids
-        sbs = sb_ref[pl.ds(s, BUCKET_CAP)]             # their bucket ids
-        ok = sbs == b
-        rid = jnp.where(ok, seg, EMPTY)
-        safe = jnp.clip(rid, 0, cap_pad - 1)
-        key = jnp.where(ok, keys_ref[safe], 0)
-        rid_ref[t, :] = rid
-        key_ref[t, :] = key
-
-
-@functools.partial(jax.jit, static_argnames=("n_buckets", "interpret"))
-def build(keys: jax.Array, valid: jax.Array, *, n_buckets: int,
-          interpret: bool = False):
-    """Pallas bulk build. Same contract as :func:`build_ref`."""
-    cap = keys.shape[0]
-    order, sb, start, overflow = _build_sorted(keys, valid, n_buckets)
-    # pad the sorted arrays so every bucket's slice stays in range
-    orderp = jnp.concatenate(
-        [order, jnp.full((BUCKET_CAP,), cap, jnp.int32)])
-    sbp = jnp.concatenate(
-        [sb, jnp.full((BUCKET_CAP,), n_buckets, jnp.int32)])
-    keysp = jnp.concatenate([keys.astype(jnp.int32),
-                             jnp.zeros((1,), jnp.int32)])
-    tb = 8  # bucket rows per grid step (one f32-tile of sublanes)
-    nblk = -(-n_buckets // tb)
-    rid, key = pl.pallas_call(
-        functools.partial(_build_kernel, tb=tb, cap_pad=cap + 1),
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((tb,), lambda i: (i,), memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY
-                         if hasattr(pltpu, "ANY") else pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY
-                         if hasattr(pltpu, "ANY") else pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY
-                         if hasattr(pltpu, "ANY") else pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((tb, BUCKET_CAP), lambda i: (i, 0)),
-            pl.BlockSpec((tb, BUCKET_CAP), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblk * tb, BUCKET_CAP), jnp.int32),
-            jax.ShapeDtypeStruct((nblk * tb, BUCKET_CAP), jnp.int32),
-        ],
-        interpret=interpret,
-    )(start, orderp, sbp, keysp)
-    return rid[:n_buckets], key[:n_buckets], overflow
-
-
 # ------------------------------------------------------------------- probe
 
 def probe_ref(rid: jax.Array, key: jax.Array, qkeys: jax.Array):
@@ -205,11 +147,15 @@ def probe_ref(rid: jax.Array, key: jax.Array, qkeys: jax.Array):
 
 
 def _probe_kernel(qk_ref, bid_ref, rid_ref, key_ref, cand_ref, hit_ref):
+    """Grid step ``i``: the index map DMA'd the aligned 8-bucket tile
+    holding query ``i``'s bucket; pick its row and store it into row ``i``
+    of the resident outputs."""
     i = pl.program_id(0)
-    k = qk_ref[i]
-    cand = rid_ref[...]
-    cand_ref[...] = cand
-    hit_ref[...] = (cand != EMPTY) & (key_ref[...] == k)
+    r = bid_ref[i] % 8
+    cand = rid_ref[pl.ds(r, 1), :]
+    hit = (cand != EMPTY) & (key_ref[pl.ds(r, 1), :] == qk_ref[i])
+    cand_ref[pl.ds(i, 1), :] = cand
+    hit_ref[pl.ds(i, 1), :] = hit.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -217,33 +163,27 @@ def probe(rid: jax.Array, key: jax.Array, qkeys: jax.Array, *,
           interpret: bool = False):
     """Pallas batched probe: the bucket id of every query rides in as a
     prefetched scalar, so the BlockSpec index map DMAs exactly the one
-    bucket tile each grid step needs. Contract of :func:`probe_ref`."""
+    (8, bucket_cap) tile that holds its bucket (the TPU's 32-bit tiling
+    forbids a one-row block). Contract of :func:`probe_ref`."""
     nb, cap_b = rid.shape
     w = qkeys.shape[0]
+    wp = -(-w // 8) * 8
     qk = qkeys.astype(jnp.int32)
     bids = bucket_of(qk, nb)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(w,),
-        in_specs=[
-            pl.BlockSpec((1, cap_b), lambda i, qk, bid: (bid[i], 0)),
-            pl.BlockSpec((1, cap_b), lambda i, qk, bid: (bid[i], 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, cap_b), lambda i, qk, bid: (i, 0)),
-            pl.BlockSpec((1, cap_b), lambda i, qk, bid: (i, 0)),
-        ],
-    )
+    tile = pl.BlockSpec((8, cap_b), lambda i, qk, bid: (bid[i] // 8, 0))
+    out = pl.BlockSpec((wp, cap_b), lambda i, qk, bid: (0, 0))
     cand, hit = pl.pallas_call(
         _probe_kernel,
-        grid_spec=grid_spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(w,),
+            in_specs=[tile, tile], out_specs=[out, out]),
         out_shape=[
-            jax.ShapeDtypeStruct((w, cap_b), jnp.int32),
-            jax.ShapeDtypeStruct((w, cap_b), jnp.bool_),
+            jax.ShapeDtypeStruct((wp, cap_b), jnp.int32),
+            jax.ShapeDtypeStruct((wp, cap_b), jnp.int32),
         ],
         interpret=interpret,
     )(qk, bids, rid, key)
-    return cand, hit
+    return cand[:w], hit[:w] != 0
 
 
 # ------------------------------------------------- incremental maintenance

@@ -32,7 +32,7 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _mode() -> str:
+def kernel_mode() -> str:
     """kernel | interpret | ref (env REPRO_KERNELS overrides)."""
     env = os.environ.get("REPRO_KERNELS")
     if env in ("kernel", "interpret", "ref"):
@@ -41,7 +41,7 @@ def _mode() -> str:
 
 
 def flash_attention(q, k, v, **kw):
-    mode = _mode()
+    mode = kernel_mode()
     if mode == "ref":
         kw.pop("block_q", None)
         kw.pop("block_kv", None)
@@ -50,7 +50,7 @@ def flash_attention(q, k, v, **kw):
 
 
 def paged_attention(q, arena, pages, lengths, **kw):
-    mode = _mode()
+    mode = kernel_mode()
     if mode == "ref":
         return ref.paged_attention_ref(q, arena, pages, lengths, **kw)
     return _paged(q, arena, pages, lengths,
@@ -67,7 +67,7 @@ def predicate_scan(cols, valid, vals, *, ops, limit, want_ids=True,
     see kernels/relscan.relscan for the full contract. ``mode`` overrides
     the REPRO_KERNELS selection (the vmapped micro-batch executor pins
     ``ref``: a [batch, cap] broadcast compare IS the fused form there)."""
-    mode = mode or _mode()
+    mode = mode or kernel_mode()
     if mode == "ref":
         return ref.relscan_ref(cols, valid, vals, ops=ops, limit=limit,
                                want_ids=want_ids)
@@ -75,24 +75,21 @@ def predicate_scan(cols, valid, vals, *, ops, limit, want_ids=True,
                     interpret=(mode == "interpret"), want_ids=want_ids, **kw)
 
 
-def hash_build(keys, valid, *, n_buckets, mode=None):
+def hash_build(keys, valid, *, n_buckets):
     """Bulk (re)build of a bucketed hash index over one int32 key column.
     Returns (rid [nb, cap_b], key [nb, cap_b], overflow scalar) — see
-    kernels/hashidx. ``mode`` overrides REPRO_KERNELS (executors that
-    rebuild inside vmapped/batched dispatches pin ``ref``)."""
-    mode = mode or _mode()
-    if mode == "ref":
-        return _hashidx.build_ref(keys, valid, n_buckets=n_buckets)
-    return _hashidx.build(keys, valid, n_buckets=n_buckets,
-                          interpret=(mode == "interpret"))
+    kernels/hashidx. One implementation on every backend: an XLA sort
+    plus gathers (a TPU kernel has nothing to add — the work is a random
+    gather, which Mosaic can only issue as one DMA per row)."""
+    return _hashidx.build(keys, valid, n_buckets=n_buckets)
 
 
 def hash_probe(rid, key, qkeys, *, mode=None):
     """Batched hash-index probe: one bucket tile per query key. Returns
     (cand [w, cap_b] row ids, hit [w, cap_b]) — see kernels/hashidx.
-    ``mode`` as in :func:`hash_build` (the vmapped micro-batch executor
+    ``mode`` overrides REPRO_KERNELS (the vmapped micro-batch executor
     pins ``ref``: batched gathers ARE the fused form there)."""
-    mode = mode or _mode()
+    mode = mode or kernel_mode()
     if mode == "ref":
         return _hashidx.probe_ref(rid, key, qkeys)
     return _hashidx.probe(rid, key, qkeys,
@@ -134,7 +131,7 @@ def shard_split(shard_ids, n_shards: int, row_mask=None):
 
 
 def mamba2_scan(x, dt, dA, B, C, **kw):
-    mode = _mode()
+    mode = kernel_mode()
     if mode == "ref":
         import jax.numpy as jnp
         b, s, nh, dh = x.shape

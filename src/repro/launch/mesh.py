@@ -21,8 +21,12 @@ def make_lane_mesh(n_devices: int):
 
     Cached so every table/executor sharing a device count sees the *same*
     Mesh object (jit cache keys and NamedSharding comparisons stay cheap
-    and stable)."""
-    return jax.make_mesh((n_devices,), (LANE_AXIS,))
+    and stable). The axis is ``Auto``: the lane executors are written
+    for GSPMD propagation around their ``shard_map`` body, and jax's
+    ``Explicit`` default would demand an ``out_sharding`` on every
+    scatter that touches the assembled lane stack."""
+    return jax.make_mesh((n_devices,), (LANE_AXIS,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def lane_mesh_for(n_shards: int, n_devices: int | None = None):
@@ -39,6 +43,19 @@ def lane_mesh_for(n_shards: int, n_devices: int | None = None):
     lim = min(int(n_shards), int(n_devices))
     d = max((k for k in range(1, lim + 1) if n_shards % k == 0), default=1)
     return make_lane_mesh(d) if d > 1 else None
+
+
+def refuse_fleet_on_accelerator(what: str) -> None:
+    """Stop ``what`` (a launcher of several daemon processes on this
+    host) when JAX's backend here is an accelerator: every daemon would
+    claim the chip, and a chip serves one process at a time. Only the
+    CPU backend (e.g. ``JAX_PLATFORMS=cpu``) runs a local fleet."""
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise SystemExit(
+            f"{what}: this host's JAX backend is {backend!r}; several "
+            "daemon processes cannot share its chip. Run one daemon per "
+            "host, or set JAX_PLATFORMS=cpu for a local CPU fleet.")
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -6,9 +6,9 @@ This is the GSPMD side of the distribution story (training / prefill):
 einsum-heavy graphs lower well under pjit with these constraints. The
 serving decode path uses ``shard_map`` instead (serving/engine.py) because
 its paged gathers must stay shard-local. Since PR 7 the cache daemon's
-sharded-table fan-out is a third client of the :func:`shard_map` compat
-shim below: ``core/shards.py`` lowers its per-lane map through it over
-the ``launch/mesh.py`` lane mesh, so the shim is now load-bearing for
+sharded-table fan-out is a third client of the :func:`shard_map`
+wrapper below: ``core/shards.py`` lowers its per-lane map through it over
+the ``launch/mesh.py`` lane mesh, so the wrapper is load-bearing for
 serving traffic, not just the model stack.
 
 Rules are *per-arch overridable*: a config may e.g. drop the
@@ -32,18 +32,12 @@ _DP = ("pod", "data")  # batch-parallel axes (outer pod, inner data/fsdp)
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
               check_vma=True):
-    """``jax.shard_map`` across jax versions: newer releases expose it at
-    the top level (axis_names/check_vma); 0.4.x only has the experimental
-    form (auto/check_rep). One call site API, either backend."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(mesh.axis_names) - frozenset(
-        axis_names if axis_names is not None else mesh.axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma, auto=auto)
+    """``jax.shard_map`` with ``axis_names=None`` meaning every mesh axis
+    is manual (jax's own default, which takes no ``None``)."""
+    kw = {} if axis_names is None else {"axis_names": frozenset(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
+
 
 # Default logical-axis -> mesh-axis rules (single- and multi-pod; missing
 # mesh axes in a rule are silently dropped against the actual mesh).

@@ -34,6 +34,7 @@ class DaemonProc:
         env = dict(os.environ)
         env["PYTHONPATH"] = (os.path.join(_REPO, "src")
                              + os.pathsep + env.get("PYTHONPATH", ""))
+        env["JAX_PLATFORMS"] = "cpu"  # a fleet on one host shares no chip
         self.proc = subprocess.Popen(
             [sys.executable, "-m", "repro.core.protocol",
              "--host", "127.0.0.1", "--port", "0"],

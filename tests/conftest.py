@@ -4,6 +4,7 @@ launch/dryrun.py forces 512 placeholder devices (in its own process).
 """
 import os
 
+import jax
 import numpy as np
 import pytest
 
@@ -12,6 +13,14 @@ import pytest
 # throwaway tables — default it off here; execache tests opt back in
 # with SQLCached(warmup=True) / explicit WARMUP statements.
 os.environ.setdefault("REPRO_WARMUP", "0")
+
+# The daemon turns JAX's persistent compilation cache on (core/execache
+# use_persistent_cache); the suite keeps it off so that no test depends
+# on what an earlier run compiled. The variable reaches daemon child
+# processes too; the update covers a jax that a plugin imported first.
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "0")
+jax.config.update("jax_enable_compilation_cache",
+                  os.environ["JAX_ENABLE_COMPILATION_CACHE"] != "0")
 
 
 @pytest.fixture(autouse=True)

@@ -74,8 +74,9 @@ def test_cache_stats_shape():
     c = ExecutorCache()
     s = c.stats_dict()
     assert set(s) == {"cached", "entries", "epoch", "hits", "misses",
-                      "compiles", "fallbacks", "compile_ms_total"}
-    assert s["cached"] == 0 and s["epoch"] == 0
+                      "compiles", "fallbacks", "compile_ms_total",
+                      "warmup_errors"}
+    assert s["cached"] == 0 and s["epoch"] == 0 and s["warmup_errors"] == []
 
 
 # ----------------------------------------------------- WARMUP + zero-recompile
@@ -125,9 +126,22 @@ def test_create_time_background_warmup():
     db = _mkdb(warmup=True)
     db.drain_warmup("t")
     st = _stats(db, "t")
-    assert st["cached"] > 0
+    assert st["cached"] > 0 and st["warmup_errors"] == []
     # everything the canonical set covers is already planned
     assert db.execute("WARMUP t").count == 0
+
+
+def test_background_warmup_failure_is_reported(monkeypatch):
+    """A compile the backend refuses during CREATE-time warm-up must show
+    in SHOW STATS, not vanish until the first live statement."""
+    def refuse(self, t, stmt):
+        raise RuntimeError("compile refused")
+
+    monkeypatch.setattr(SQLCached, "_warm_statement", refuse)
+    db = _mkdb(warmup=True)
+    db.drain_warmup("t")
+    errs = _stats(db, "t")["warmup_errors"]
+    assert len(errs) == 1 and "RuntimeError: compile refused" in errs[0]
 
 
 def test_explain_reports_preplanned():
