@@ -288,7 +288,8 @@ class _HostStack:
 
     def host(self) -> dict:
         if self._np is None:
-            with self._lock:
+            # a sibling that finds the stack synced waits for nothing
+            with TEL.device_wait(), self._lock:
                 if self._np is None:
                     self._np = jax.tree.map(np.asarray, self.dev)
         return self._np
@@ -331,7 +332,8 @@ class Result:
         stack = self._ctx.get("stack")
         if stack is not None:
             return stack.host()[name][self._ctx["index"]]
-        return np.asarray(self._dev[name])
+        with TEL.device_wait():
+            return np.asarray(self._dev[name])
 
     # ------------------------------------------------- lazy host accessors
     @property
@@ -371,8 +373,9 @@ class Result:
             i = self._ctx["index"]
             arrays = {c: stack.host()["rows"][c][i][:shown] for c in columns}
         else:
-            arrays = {c: np.asarray(self._dev["rows"][c])[:shown]
-                      for c in columns}
+            with TEL.device_wait():
+                arrays = {c: np.asarray(self._dev["rows"][c])[:shown]
+                          for c in columns}
         rows = []
         for i in range(shown):
             if not present[i]:
@@ -524,6 +527,26 @@ def _bucket(n: int) -> int:
     return b
 
 
+def _exec_name(kind: str, probe: bool | None = None,
+               batch: bool = False) -> str:
+    """The name of a statement executor's program,
+    ``sqlcached_<kind>[_probe|_scan][_batch]``: ``kind`` is the statement
+    (an aggregate by its function), ``probe`` its access path (None: it
+    has none), ``batch`` the grouped path. Jit calls the program
+    ``jit_<name>``, which is what a profiler's ``XLA Modules`` line
+    shows."""
+    name = "sqlcached_" + kind.lower()
+    if probe is not None:
+        name += "_probe" if probe else "_scan"
+    return name + "_batch" if batch else name
+
+
+def _named(fn, name: str):
+    """``fn`` renamed: jit names a program after its function."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 def _np_terms_int(terms, param_cols) -> bool:
     """Host-side dtype gate for the batched probe route: every `?`-bound
     term value must be integer (floats keep exact-compare semantics on
@@ -626,13 +649,14 @@ class SQLCached:
                   sid) -> None:
         t.execs.note_sig(self._sig(t, stmt, kind, b, mode, sid))
 
-    def _jit_with_expiry(self, schema, base, eng=T):
+    def _jit_with_expiry(self, schema, base, name: str, eng=T):
         """Jit a statement executor ``base(state, *args) -> (state, *outs)``
         with the §4.3 op-count expiry fused into the same dispatch: a
         device-side ``lax.cond`` on a host-computed flag replaces the former
         separate ``_do_expire`` call, so auto-expiry is dispatch-free.
         ``eng`` is the table's engine module (expiry must run the
-        matching state layout)."""
+        matching state layout); ``name`` names the program
+        (:func:`_exec_name`)."""
         if schema.expiry.ops_interval > 0:
             def fn(state, expire_flag, *args):
                 out = base(state, *args)
@@ -645,11 +669,12 @@ class SQLCached:
         else:
             def fn(state, expire_flag, *args):
                 return base(state, *args)
-        return jax.jit(fn, donate_argnums=0)
+        return jax.jit(_named(fn, name), donate_argnums=0)
 
-    def _jit_exec(self, xsch, base, mode: str, eng):
+    def _jit_exec(self, xsch, base, mode: str, eng, name: str):
         """Jit ``base(state, *args) -> (state, *outs)`` for one dispatch
-        shape (see :meth:`_exec_mode`), fusing the §4.3 op-count expiry
+        shape (see :meth:`_exec_mode`) as the program ``name``
+        (:func:`_exec_name`), fusing the §4.3 op-count expiry
         and — on lanes — the lazy clock catch-up into the same dispatch:
 
         * ``mono``:    ``fn(state, flag, *args)`` (the classic wrapper);
@@ -670,7 +695,7 @@ class SQLCached:
           state is pinned back onto the mesh so the caller's
           disassembly is a per-device slice, not a gather."""
         if mode == "mono":
-            return self._jit_with_expiry(xsch, base, eng=eng)
+            return self._jit_with_expiry(xsch, base, name, eng=eng)
         iv = xsch.expiry.ops_interval
         if mode == "lane":
             def fn(state, expire_flag, delta, pre_delta, *args):
@@ -700,7 +725,7 @@ class SQLCached:
                         lambda s: s, st)
                 return (st,) + tuple(out[1:])
 
-            return jax.jit(fn, donate_argnums=0)
+            return jax.jit(_named(fn, name), donate_argnums=0)
 
         schema = xsch  # stacked/mesh modes run on the full sharded schema
 
@@ -745,7 +770,7 @@ class SQLCached:
                                 deltas, pre_deltas, *args)
                 return (tuple(SH.split_lanes(schema, st)),) + tuple(outs)
 
-        return jax.jit(fn, donate_argnums=0)
+        return jax.jit(_named(fn, name), donate_argnums=0)
 
     def _lane_of(self, t: _Table, stmt, params_list,
                  pvals=None) -> int | None:
@@ -1627,7 +1652,8 @@ class SQLCached:
         key = (mode, "flush", t.schema)
         fn = self._executor(
             t, key, lambda: self._jit_exec(
-                t.schema, lambda st: SH.flush(t.schema, st), mode, SH))
+                t.schema, lambda st: SH.flush(t.schema, st), mode, SH,
+                _exec_name("flush")))
         n, = self._run_state(t, fn, mode, None, False, 1, ())
         return Result(dev={"count": n})
 
@@ -1754,14 +1780,20 @@ class SQLCached:
         with TEL.dispatch_span([tr]):
             res = self._dispatch_stmt(stmt.inner, params)
             tr.mark("execute")
-            count = res.count
-            _ = res.rows
-            _ = res.value
-            tr.mark("render")
+            TEL.render_begin(tr)
+            try:
+                count = res.count
+                _ = res.rows
+                _ = res.value
+            finally:
+                TEL.render_end(tr)
+        # the six stages sum to the wall clock; children lie inside them
         info = {"analyze": True,
                 "plan": plan,
                 "stages": {k: round(v, 1)
                            for k, v in tr.stage_totals().items()},
+                "children": {k: round(v, 1)
+                             for k, v in tr.child_totals().items()},
                 "total_us": round((tr.last - tr.t0) * 1e6, 1),
                 "count": count}
         if tr.mode is not None:
@@ -2170,7 +2202,8 @@ class SQLCached:
                     slots = slots + off_d  # globalize this lane's row ids
                 return state, slots, ev
 
-            return self._jit_exec(xsch, base, mode, eng)
+            return self._jit_exec(xsch, base, mode, eng,
+                                  _exec_name("insert", batch=True))
 
         fn = self._executor(t, key, build)
         if _warm is not None:
@@ -2273,7 +2306,9 @@ class SQLCached:
                                               vals, active,
                                               per_statement=per_statement)
 
-                return self._jit_exec(xsch, base, mode, eng)
+                return self._jit_exec(
+                    xsch, base, mode, eng,
+                    _exec_name("delete", False, batch=True))
 
             def base(state, param_cols, active):
                 if is_delete:
@@ -2334,7 +2369,12 @@ class SQLCached:
                              ops=state["ops"] - pad)
                 return state, jnp.sum(ns), ns
 
-            return self._jit_exec(xsch, base, mode, eng)
+            return self._jit_exec(
+                xsch, base, mode, eng,
+                _exec_name("delete", False, batch=True) if is_delete
+                else _exec_name("update",
+                                isinstance(update_plan, PL.IndexProbe),
+                                batch=True))
 
         fn = self._executor(t, key, build)
         kind = "delete" if is_delete else "update"
@@ -2439,7 +2479,8 @@ class SQLCached:
                         res["present"], res["row_ids"] + off_d, 0))
                 return state, res
 
-            return self._jit_exec(xsch, base, mode, eng)
+            return self._jit_exec(xsch, base, mode, eng,
+                                  _exec_name("select", probe, batch=True))
 
         fn = self._executor(t, key, build)
         off = sid * SH.shard_capacity(schema) if mode == "lane" else 0
@@ -2517,7 +2558,8 @@ class SQLCached:
                              ops=state["ops"] + nact)
                 return state, vals
 
-            return self._jit_exec(xsch, base, mode, eng)
+            return self._jit_exec(xsch, base, mode, eng,
+                                  _exec_name(agg, probe, batch=True))
 
         fn = self._executor(t, key, build)
         vals, = self._run_state(t, fn, mode, sid, flag, n,
@@ -2552,6 +2594,8 @@ class SQLCached:
                     lambda st, pr: eng.aggregate(xsch, st, agg, col,
                                                  where, pr),
                     mode, eng,
+                    _exec_name(agg, isinstance(eng.plan_for(xsch, where),
+                                               PL.IndexProbe)),
                 ),
             )
             if _warm is not None:
@@ -2578,7 +2622,10 @@ class SQLCached:
                     res = dict(res, row_ids=jnp.where(
                         res["present"], res["row_ids"] + off_d, 0))
                 return st, res
-            return self._jit_exec(xsch, base, mode, eng)
+            plan = eng.plan_for(xsch, where, ranked=stmt.order_by is not None)
+            return self._jit_exec(
+                xsch, base, mode, eng,
+                _exec_name("select", isinstance(plan, PL.IndexProbe)))
 
         fn = self._executor(t, key, build)
         if _warm is not None:
@@ -2619,7 +2666,9 @@ class SQLCached:
         def build():
             def base(st, pr):
                 return eng.update(xsch, st, where, dict(sets), pr)
-            return self._jit_exec(xsch, base, mode, eng)
+            probe = isinstance(eng.plan_for(xsch, where), PL.IndexProbe)
+            return self._jit_exec(xsch, base, mode, eng,
+                                  _exec_name("update", probe))
 
         fn = self._executor(t, key, build)
         if _warm is not None:
@@ -2666,7 +2715,9 @@ class SQLCached:
                     return st, n, ids, present
                 st, n = eng.delete(xsch, st, where, pr)
                 return st, n
-            return self._jit_exec(xsch, base, mode, eng)
+            probe = isinstance(eng.plan_for(xsch, where), PL.IndexProbe)
+            return self._jit_exec(xsch, base, mode, eng,
+                                  _exec_name("delete", probe))
 
         fn = self._executor(t, key, build)
         if _warm is not None:
@@ -2699,7 +2750,8 @@ class SQLCached:
         key = (mode, "expire", t.schema)
         fn = self._executor(
             t, key, lambda: self._jit_exec(
-                t.schema, lambda st: SH.expire(t.schema, st), mode, SH))
+                t.schema, lambda st: SH.expire(t.schema, st), mode, SH,
+                _exec_name("expire")))
         # (_run_state's stacked booking consumed every lane deferral and
         # the dispatch replayed them — nothing left to clear here)
         n, = self._run_state(t, fn, mode, None, False, 1, ())

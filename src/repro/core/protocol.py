@@ -255,13 +255,17 @@ def _render_burst(items: list) -> tuple[bytes, int, int, list]:
     order. Returns (wire bytes, n statements ok, n statement errors,
     [trace] for traced items, ``trace.error`` stamped). Sibling Results of one batch
     share a device→host sync here, and each statement's trace gets its
-    "render" span stamped at render time — but the histogram fold
+    "render" span stamped at render time (its "respond_wait" child ends
+    where its own item starts, its "device_wait" child is the time
+    blocked in the sync) — but the histogram fold
     (``Telemetry.finish``) is the CALLER's job, after the bytes are on
     the socket, so recording never adds to the client-visible latency."""
     parts: list[bytes] = []
     stmts = errs = 0
     done: list = []
     for tag, payload, trace in items:
+        if trace is not None:
+            TEL.render_begin(trace)
         err = False
         if isinstance(payload, Exception):
             msg = str(payload).replace("\n", " ")[:500]
@@ -280,7 +284,7 @@ def _render_burst(items: list) -> tuple[bytes, int, int, list]:
                 errs += 1
                 err = True
         if trace is not None:
-            trace.mark("render")
+            TEL.render_end(trace)
             if err:
                 trace.error = True
             done.append(trace)
@@ -361,7 +365,6 @@ class _ResponseQueue:
         self._writer = writer
         self._server = server
         self._telemetry = server.db.telemetry
-        self._ring = self._telemetry.ring()  # per-connection trace ring
         self._q: asyncio.Queue = asyncio.Queue(maxsize=1024)
         self._task = asyncio.create_task(self._run())
 
@@ -410,8 +413,7 @@ class _ResponseQueue:
                 # finish() is an O(1) enqueue — the histogram fold runs
                 # in telemetry's background folder thread, never here
                 for trace in done:
-                    self._telemetry.finish(trace, ring=self._ring,
-                                           error=trace.error)
+                    self._telemetry.finish(trace, error=trace.error)
             except (ConnectionError, OSError):
                 # peer went away mid-write. Keep CONSUMING until the close
                 # sentinel — the handler may be parked on the bounded

@@ -296,9 +296,9 @@ class BatchScheduler:
         except Exception:
             shape = None  # unparseable: barrier; execute() re-raises for us
         if trace is not None:
-            trace.mark("parse")
             if shape is not None:
                 trace.table, trace.kind = shape.table, shape.kind
+            trace.mark("parse")
         self._q.append(_Item(sql, tuple(params), fut, shape, self._now(),
                              trace))
         self.stats.add("admitted")
@@ -346,16 +346,17 @@ class BatchScheduler:
     def _call_traced(fn, traces, *args, **kwargs):
         """Run ``fn`` in the worker thread with ``traces`` installed as
         the ambient dispatch context (so daemon/execache attribute
-        exec_mode and cache events into them) and stamp the "execute"
-        span on each trace when it returns."""
+        exec_mode and cache events into them, and a profiler session sees
+        one ``sqlcached.dispatch`` span) and stamp the "execute" span on
+        each trace when it returns."""
         if not traces:
             return fn(*args, **kwargs)
-        with TEL.dispatch_span(traces):
-            try:
+        try:
+            with TEL.dispatch_span(traces):
                 return fn(*args, **kwargs)
-            finally:
-                for tr in traces:
-                    tr.mark("execute")
+        finally:
+            for tr in traces:
+                tr.mark("execute")
 
     async def _run_single(self, it: _Item) -> None:
         traces = [it.trace] if it.trace is not None else ()
@@ -458,7 +459,8 @@ class BatchScheduler:
         locks = self._locks_for(g)
         for it in g.items:
             if it.trace is not None:
-                it.trace.mark("queue")   # admission -> lock acquisition
+                # admission -> lock acquisition; its cut -> here
+                it.trace.mark("queue", child="wave_wait")
         for lk in locks:
             await lk.acquire()
         for it in g.items:
@@ -611,7 +613,10 @@ class BatchScheduler:
                     return
             items: list[_Item] = []
             while self._q and len(items) < self.max_admit:
-                items.append(self._q.popleft())
+                it = self._q.popleft()
+                if it.trace is not None:
+                    it.trace.child("cut_wait")   # admission -> this cut
+                items.append(it)
             if self._q:
                 self._wake.set()  # leftovers past max_admit: next tick
             groups = self._plan(items)

@@ -1,9 +1,9 @@
 """Host-side serving telemetry: trace spans, histograms, slow log (PR 9).
 
 Observability layer for the serving path.  Everything here is *host only*
-— no jax import, no device handle is ever touched — so recording a span
-or reading a report can never force a device sync or a
-``.block_until_ready()`` on the serving path.
+— no device handle is ever touched; of JAX it uses only the profiler's
+``TraceAnnotation`` — so recording a span or reading a report can never
+force a device sync or a ``.block_until_ready()`` on the serving path.
 
 Pieces
 ------
@@ -20,6 +20,34 @@ Pieces
         lock    lane/table lock wait
         execute the db.execute/executemany call (includes compile on miss)
         render  response render + lazy-result materialisation at flush
+
+    Child spans split two of them; each lies inside its parent's
+    interval, and the parent's self time is its duration minus its
+    children (``CHILDREN``)::
+
+        cut_wait      (queue)  admission -> the scheduler loop cuts the
+                               statement off its queue
+        wave_wait     (queue)  that cut -> its group's dispatch start,
+                               behind earlier conflicting waves
+        respond_wait  (render) end of execute -> the statement's own item
+                               starts rendering (in-order wait on a
+                               pipelined connection, plus the hops)
+        device_wait   (render) blocked in the device->host transfer of
+                               the statement's result
+
+    ``child(name)`` closes a child that starts where the previous child
+    (or the parent) ended; ``device_wait`` is charged by the
+    :class:`device_wait` context around each blocking transfer.
+
+    While a profiler session is active (checked once, when the trace is
+    made), every stage and child is also written as a
+    ``jax.profiler.TraceAnnotation`` named ``sqlcached.<stage>``, entered
+    at the mark that opens it and exited at the mark that closes it, with
+    the trace's ``id`` and (on spans opened after the parse) the shape
+    ``kind`` as metadata; the dispatch context adds one
+    ``sqlcached.dispatch`` span around the worker-thread db call (group
+    size and executor-cache event).  Outside a session no annotation
+    object is built.
 
     Attribution fields (``mode``, ``cache``, ``compile_ms``, ``group``,
     ``wave``) are filled in by the dispatch layers via the thread-local
@@ -41,8 +69,8 @@ Pieces
 
 ``Telemetry``
     Per-``SQLCached`` aggregator: per-(table, kind) histograms + stage /
-    mode / cache attribution, per-connection rings, and the bounded
-    slow-statement ring (``SQLCached(slow_ms=...)`` / ``REPRO_SLOW_MS``).
+    mode / cache attribution and the bounded slow-statement ring
+    (``SQLCached(slow_ms=...)`` / ``REPRO_SLOW_MS``).
     Disabled entirely with ``REPRO_TELEMETRY=0`` (``trace()`` returns
     None and the serving path pays nothing but a None check).
     ``finish`` is an O(1) enqueue: the per-shape histogram fold runs in
@@ -61,12 +89,15 @@ signatures.
 
 from __future__ import annotations
 
+import itertools
 import math
 import os
 import threading
 import time
 from collections import deque
 from typing import Any, Iterator
+
+from jax.profiler import TraceAnnotation
 
 from repro.lint import lockorder as LK
 
@@ -78,11 +109,14 @@ __all__ = [
     "bucket_of",
     "bucket_bounds",
     "current_traces",
+    "device_wait",
     "dispatch_span",
     "merge_reports",
     "note_exec",
     "note_mode",
     "prom",
+    "render_begin",
+    "render_end",
 ]
 
 # 2^0 .. 2^(N_BUCKETS-1) microseconds; the last bucket absorbs the tail
@@ -275,21 +309,38 @@ class _ShapeStats:
 # of a daemon's object graph are milliseconds, and they land on whatever
 # statement is in flight).
 STAGES = ("wire", "parse", "queue", "lock", "execute", "render")
-_SLOT = {s: "s_" + s for s in STAGES}
+# child span -> the stage whose interval holds it
+CHILDREN = {"cut_wait": "queue", "wave_wait": "queue",
+            "respond_wait": "render", "device_wait": "render"}
+_SLOT = {s: "s_" + s for s in STAGES + tuple(CHILDREN)}
 _STAGE_KEYS = tuple((s, "s_" + s, s + ".us", s + ".n") for s in STAGES)
+_CHILD_KEYS = tuple((c, "s_" + c, "s_" + p, c + ".us", c + ".n")
+                    for c, p in CHILDREN.items())
+# profiler spans: the stage (and its first child) that a stage's mark
+# opens, and the child that a closed child's end opens
+_ANN = {s: "sqlcached." + s for s in _SLOT}
+_OPENS = {s: (n, {"queue": "cut_wait", "render": "respond_wait"}.get(n))
+          for s, n in zip(STAGES, STAGES[1:] + (None,))}
+_FOLLOWS = {c: "wave_wait" if c == "cut_wait" else None for c in CHILDREN}
+_IDS = itertools.count(1)
 
 
 class Trace:
     """Per-statement trace context; spans are per-stage delta_us slots."""
 
-    __slots__ = ("t0", "last", "s_wire", "s_parse", "s_queue", "s_lock",
-                 "s_execute", "s_render", "sql", "table", "kind",
+    __slots__ = ("t0", "last", "sub", "s_wire", "s_parse", "s_queue",
+                 "s_lock", "s_execute", "s_render", "s_cut_wait",
+                 "s_wave_wait", "s_respond_wait", "s_device_wait", "id",
+                 "prof", "_ann", "_cann", "sql", "table", "kind",
                  "mode", "cache", "compile_ms", "group", "wave", "error")
 
     def __init__(self, sql: str | None = None):
-        self.t0 = self.last = time.perf_counter()
+        self.t0 = self.last = self.sub = time.perf_counter()
         self.s_wire = self.s_parse = self.s_queue = 0.0
         self.s_lock = self.s_execute = self.s_render = 0.0
+        self.s_cut_wait = self.s_wave_wait = 0.0
+        self.s_respond_wait = self.s_device_wait = 0.0
+        self.id = next(_IDS)
         self.sql = sql
         self.table: str | None = None
         self.kind: str | None = None
@@ -299,12 +350,57 @@ class Trace:
         self.group: int | None = None
         self.wave: int | None = None
         self.error = False
+        # the one per-statement check for a profiler session
+        self.prof = TraceAnnotation.is_enabled()
+        self._cann = None
+        self._ann = self._span("wire") if self.prof else None
 
-    def mark(self, stage: str) -> None:
+    def mark(self, stage: str, child: str | None = None) -> None:
+        """Close ``stage`` (from the previous mark to now); ``child``
+        names its last child span, which closes at the same instant."""
         now = time.perf_counter()
+        if child is not None:
+            slot = _SLOT[child]
+            setattr(self, slot, getattr(self, slot) + (now - self.sub) * 1e6)
         slot = _SLOT[stage]
         setattr(self, slot, getattr(self, slot) + (now - self.last) * 1e6)
-        self.last = now
+        self.last = self.sub = now
+        if self.prof:
+            if self._cann is not None:
+                self._cann.__exit__(None, None, None)
+                self._cann = None
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+                self._ann = None
+            nxt, first = _OPENS[stage]
+            if nxt is not None:
+                self._ann = self._span(nxt)
+                if first is not None:
+                    self._cann = self._span(first)
+
+    def child(self, name: str) -> None:
+        """Close child span ``name``: from where the previous child (or
+        the parent stage) ended to now."""
+        now = time.perf_counter()
+        slot = _SLOT[name]
+        setattr(self, slot, getattr(self, slot) + (now - self.sub) * 1e6)
+        self.sub = now
+        if self.prof:
+            if self._cann is not None:
+                self._cann.__exit__(None, None, None)
+            nxt = _FOLLOWS[name]
+            self._cann = None if nxt is None else self._span(nxt)
+
+    def _span(self, name: str) -> TraceAnnotation:
+        """Enter the profiler span ``sqlcached.<name>``. Its metadata rides
+        in the name (``name#key=value,...#``, TraceMe's own encoding, which
+        the trace reads back as stats): about half the cost of keyword
+        metadata, on a host that the profiler already slows."""
+        meta = (f"#id={self.id}#" if self.kind is None
+                else f"#id={self.id},kind={self.kind}#")
+        ann = TraceAnnotation(_ANN[name] + meta)
+        ann.__enter__()
+        return ann
 
     @property
     def spans(self) -> list[tuple[str, float]]:
@@ -316,13 +412,23 @@ class Trace:
     def stage_totals(self) -> dict[str, float]:
         return dict(self.spans)
 
+    def child_totals(self) -> dict[str, float]:
+        """Child span -> delta_us, for the children whose parent stage was
+        marked (0.0 where the child took no time).  Children are not
+        stages: they are never added into :meth:`stage_totals`."""
+        return {c: getattr(self, slot) for c, slot, pslot, _, _ in _CHILD_KEYS
+                if getattr(self, pslot)}
+
     def to_dict(self) -> dict:
         d = {
+            "id": self.id,
             "sql": self.sql,
             "table": self.table,
             "kind": self.kind,
             "total_us": round((self.last - self.t0) * 1e6, 1),
             "stages": {k: round(v, 1) for k, v in self.stage_totals().items()},
+            "children": {k: round(v, 1)
+                         for k, v in self.child_totals().items()},
         }
         if self.mode is not None:
             d["mode"] = self.mode
@@ -349,21 +455,71 @@ class dispatch_span:
 
     A plain class-based context manager (not ``@contextmanager``): it
     sits on the per-statement dispatch path, where the generator
-    machinery is measurable overhead.
+    machinery is measurable overhead.  When the first trace is profiled,
+    the context is also one ``sqlcached.dispatch`` profiler span: the
+    traces' ids, the group size, and the executor-cache event.
     """
 
-    __slots__ = ("_traces", "_prev")
+    __slots__ = ("_traces", "_prev", "_ann")
 
     def __init__(self, traces):
         self._traces = [t for t in traces if t is not None] or None
 
     def __enter__(self):
         self._prev = getattr(_TLS, "traces", None)
-        _TLS.traces = self._traces
-        return self._traces
+        traces = _TLS.traces = self._traces
+        self._ann = None
+        if traces and traces[0].prof:
+            tr = traces[0]
+            self._ann = TraceAnnotation(
+                f"sqlcached.dispatch#id={tr.id},"
+                f"ids={';'.join([str(t.id) for t in traces])},"
+                f"group={len(traces)},kind={tr.kind or '-'}#")
+            self._ann.__enter__()
+        return traces
 
     def __exit__(self, exc_type, exc, tb):
+        if self._ann is not None:
+            self._ann.set_metadata(cache=self._traces[0].cache or "-")
+            self._ann.__exit__(None, None, None)
         _TLS.traces = self._prev
+        return False
+
+
+def render_begin(trace: Trace) -> None:
+    """The statement's own item starts rendering on this thread: close its
+    ``respond_wait`` child, and charge the device waits of this thread to
+    it until :func:`render_end`."""
+    trace.child("respond_wait")
+    _TLS.render = trace
+
+
+def render_end(trace: Trace) -> None:
+    """Close the statement's ``render`` stage."""
+    _TLS.render = None
+    trace.mark("render")
+
+
+class device_wait:
+    """Charge the time inside to the ``device_wait`` child of the statement
+    this thread is rendering (nothing when it renders none).  Wraps each
+    blocking device->host transfer of a lazy result."""
+
+    __slots__ = ("_tr", "_t", "_ann")
+
+    def __enter__(self):
+        tr = self._tr = getattr(_TLS, "render", None)
+        if tr is not None:
+            self._ann = tr._span("device_wait") if tr.prof else None
+            self._t = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        tr = self._tr
+        if tr is not None:
+            tr.s_device_wait += (time.perf_counter() - self._t) * 1e6
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
         return False
 
 
@@ -387,7 +543,6 @@ def note_exec(event: str, compile_ms: float = 0.0) -> None:
 class Telemetry:
     """Per-daemon telemetry aggregator (one per ``SQLCached``)."""
 
-    RING_SIZE = 256
     SLOW_SIZE = 128
     FOLD_INTERVAL_S = 0.05     # background folder poll period
     FOLD_IDLE_EXIT = 40        # idle polls (~2s) before the folder exits
@@ -419,16 +574,10 @@ class Telemetry:
             return None
         return Trace(sql)
 
-    def ring(self) -> deque:
-        """Fresh per-connection ring of finished :class:`Trace` objects
-        (rendered to dicts only when read, never on the serving path)."""
-        return deque(maxlen=self.RING_SIZE)
-
-    def finish(self, trace: Trace, ring: deque | None = None,
-               error: bool = False) -> float:
+    def finish(self, trace: Trace, error: bool = False) -> float:
         """Record a finished trace; returns its total latency in us.
 
-        O(1) on purpose: two deque appends and a thread-liveness check.
+        O(1) on purpose: one deque append and a thread-liveness check.
         Folding the trace into per-shape histograms/counters costs a few
         microseconds of pure-python work, but doing it inline — even
         after the response bytes are on the wire — showed up as tens of
@@ -439,10 +588,6 @@ class Telemetry:
         fold-on-read backstop in :meth:`report` / :meth:`slow_entries`.
         """
         total_us = (trace.last - trace.t0) * 1e6
-        # rings hold the Trace objects themselves; dict rendering happens
-        # at SHOW time, never on the serving path
-        if ring is not None:
-            ring.append(trace)
         if error:
             trace.error = True
         self._pending.append(trace)
@@ -484,6 +629,11 @@ class Telemetry:
                 v = getattr(trace, slot)
                 if v:
                     d[kus] = d.get(kus, 0) + v
+                    d[kn] = d.get(kn, 0) + 1
+            # a child counts with its parent, so its mean is per statement
+            for _, slot, pslot, kus, kn in _CHILD_KEYS:
+                if getattr(trace, pslot):
+                    d[kus] = d.get(kus, 0) + getattr(trace, slot)
                     d[kn] = d.get(kn, 0) + 1
         if trace.mode is not None:
             ss.modes.add(trace.mode)

@@ -182,6 +182,7 @@ def probe(rid: jax.Array, key: jax.Array, qkeys: jax.Array, *,
             jax.ShapeDtypeStruct((wp, cap_b), jnp.int32),
         ],
         interpret=interpret,
+        name="hashidx_probe",
     )(qk, bids, rid, key)
     return cand[:w], hit[:w] != 0
 
@@ -236,6 +237,7 @@ def insert_update(idx: dict, slots: jax.Array, old_keys: jax.Array,
     return {"rid": rid, "key": key, "stale": stale}
 
 
+@jax.named_scope("hashidx_upkeep")
 def insert_update_batched(idx: dict, slots: jax.Array, old_keys: jax.Array,
                           new_keys: jax.Array, row_mask: jax.Array,
                           valid: jax.Array) -> dict:
@@ -259,7 +261,8 @@ def insert_update_batched(idx: dict, slots: jax.Array, old_keys: jax.Array,
     differ from the sequential path when one member's clear frees a lane
     an earlier member then takes — probes never read lane order, so the
     entry set is what matters (tests/test_hashidx.py compares per-bucket
-    entry sets against the loop)."""
+    entry sets against the loop). Its ops carry the name scope
+    ``hashidx_upkeep``."""
     nb, cap_b = idx["rid"].shape
     n = slots.shape[0]
     cap = valid.shape[0]

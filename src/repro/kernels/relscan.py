@@ -187,6 +187,7 @@ def relscan(cols: Sequence[jax.Array], valid: jax.Array, vals: jax.Array, *,
             jax.ShapeDtypeStruct((nblk, 8, LANES), jnp.int32),
         ],
         interpret=interpret,
+        name="relscan_scan",
     )(vals1, *cols2, valid2)
 
     cnt = cnt[:, 0, 0]
@@ -214,6 +215,7 @@ def relscan(cols: Sequence[jax.Array], valid: jax.Array, vals: jax.Array, *,
         ),
         out_shape=jax.ShapeDtypeStruct((limitp, LANES), jnp.int32),
         interpret=interpret,
+        name="relscan_compact",
     )(offs, cnt, tidx, mask2)
 
     ids = jnp.sum(acc, axis=1)[:limit]

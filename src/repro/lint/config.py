@@ -61,7 +61,8 @@ SERVING_FUNCS: dict[str, frozenset] = {
     "core/telemetry.py": frozenset({
         "trace", "finish", "mark", "fold", "_fold_one", "_fold_loop",
         "record", "add", "max", "bulk", "bucket_of", "note_mode",
-        "note_exec", "current_traces", "ring", "spans", "stage_totals",
+        "note_exec", "current_traces", "spans", "stage_totals", "child",
+        "render_begin", "render_end", "_span",
     }),
     "core/execache.py": frozenset({
         "get", "__call__", "preplanned", "note_sig",

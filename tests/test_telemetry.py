@@ -249,6 +249,170 @@ def test_explain_analyze_stages_sum_to_wall_clock(server, client):
     assert r2["value"]["cache"] == "hit"
 
 
+def _served_mix(server, conns=4, n=24):
+    """Pipelined INSERT / point SELECT / COUNT / DELETE from ``conns``
+    connections at once, so groups, waves and in-order waits all occur."""
+    boot = SQLCachedClient(*server.addr)
+    boot.execute("CREATE TABLE cw (k INT, u INT, INDEX (k)) CAPACITY 512")
+    boot.close()
+
+    def worker(i):
+        c = SQLCachedClient(*server.addr)
+        p = c.pipeline()
+        for j in range(n):
+            k = i * n + j
+            p.execute("INSERT INTO cw (k, u) VALUES (?, ?)", [k, k % 5])
+            p.execute("SELECT u FROM cw WHERE k = ?", [k])
+            p.execute("SELECT COUNT(*) FROM cw WHERE u = ?", [j % 5])
+            if j % 4 == 3:
+                p.execute("DELETE FROM cw WHERE k = ?", [k - 1])
+        p.collect()
+        c.close()
+
+    ts = [threading.Thread(target=worker, args=(i,)) for i in range(conns)]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join()
+
+
+def test_child_spans_lie_inside_their_parents(server):
+    """The four child spans split ``queue`` and ``render`` without moving
+    them: per statement the six stages still run end to end from wire
+    receipt to the render flush, ``queue`` is exactly ``cut_wait`` +
+    ``wave_wait``, and ``respond_wait`` + ``device_wait`` fit inside
+    ``render``; SHOW METRICS counts each child once per statement of
+    its parent, so the means are per statement."""
+    server.server.db.telemetry.slow_ms = 0.0   # keep every span tree
+    _served_mix(server)
+    c = SQLCachedClient(*server.addr)
+    shapes = c.execute("SHOW METRICS cw")["value"]["shapes"]
+    slow = c.execute("SHOW SLOW")["rows"]
+    c.close()
+    for kind in ("insert", "select", "delete"):
+        st = shapes[f"cw.{kind}"]["stages"]
+        for child, parent in TEL.CHILDREN.items():
+            assert st[child]["count"] == st[parent]["count"] > 0
+        assert (st["cut_wait"]["total_us"] + st["wave_wait"]["total_us"]
+                <= st["queue"]["total_us"] + 1.0)
+        assert (st["respond_wait"]["total_us"]
+                + st["device_wait"].get("total_us", 0.0)
+                <= st["render"]["total_us"] + 1.0)
+    served = [e for e in slow if e["table"] == "cw"]
+    assert len(served) >= TEL.Telemetry.SLOW_SIZE - 4   # the newest
+    for e in served:
+        stages, ch = e["stages"], e["children"]
+        assert set(stages) == set(TEL.STAGES)
+        assert set(ch) == set(TEL.CHILDREN)
+        # the parents partition the statement's life, as before children
+        assert sum(stages.values()) == pytest.approx(e["total_us"], abs=1.0)
+        assert ch["cut_wait"] + ch["wave_wait"] == pytest.approx(
+            stages["queue"], abs=0.3)
+        assert ch["respond_wait"] + ch["device_wait"] <= \
+            stages["render"] + 0.3
+        assert min(ch.values()) >= 0
+
+
+def test_explain_analyze_reports_children_apart(server, client):
+    """EXPLAIN ANALYZE lists the child spans beside the six stages and
+    never adds them into the stages, which still sum to the total."""
+    client.execute(
+        "CREATE TABLE ec (k INT, w FLOAT, INDEX (k)) CAPACITY 64")
+    client.execute("INSERT INTO ec (k, w) VALUES (?, ?)", [1, 2.5])
+    info = client.execute(
+        "EXPLAIN ANALYZE SELECT w FROM ec WHERE k = ?", [1])["value"]
+    assert set(info["stages"]) <= set(TEL.STAGES)
+    assert set(info["children"]) == set(TEL.CHILDREN)
+    assert sum(info["stages"].values()) <= info["total_us"] * 1.001
+    ch = info["children"]
+    assert ch["cut_wait"] + ch["wave_wait"] <= info["stages"]["queue"] + 0.3
+    assert ch["respond_wait"] + ch["device_wait"] <= \
+        info["stages"]["render"] + 0.3
+
+
+def _annotations(trace_dir) -> list:
+    """(name, start_ns, end_ns, stats) of every ``sqlcached.*`` event."""
+    import pathlib
+    from jax.profiler import ProfileData
+    path = sorted(pathlib.Path(trace_dir).glob(
+        "plugins/profile/*/*.xplane.pb"))[-1]
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("sqlcached."):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns, dict(ev.stats)))
+    return out
+
+
+def test_profiler_sees_the_spans_of_each_statement(tmp_path):
+    """Inside a profiler session every stage and child of a served
+    statement is a ``sqlcached.<stage>`` annotation carrying its trace id
+    and (once parsed) kind, children inside their parents; each dispatch
+    is one ``sqlcached.dispatch`` span naming its statements and group
+    size."""
+    with ThreadedServer() as s:
+        s.server.db.telemetry.slow_ms = 0.0
+        c = SQLCachedClient(*s.addr)
+        c.execute("CREATE TABLE pa (k INT, u INT, INDEX (k)) CAPACITY 128")
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            p = c.pipeline()
+            for i in range(8):
+                p.execute("INSERT INTO pa (k, u) VALUES (?, ?)", [i, i])
+                p.execute("SELECT u FROM pa WHERE k = ?", [i])
+            p.collect()
+            slow = c.execute("SHOW SLOW")["rows"]
+        finally:
+            jax.profiler.stop_trace()
+        c.close()
+    served = {e["id"]: e for e in slow
+              if e["table"] == "pa" and e["kind"] != "admin"}
+    assert len(served) == 16
+    spans: dict = {}
+    dispatched = set()
+    for name, a, b, stats in _annotations(tmp_path):
+        if name == "sqlcached.dispatch":
+            ids = {int(x) for x in str(stats["ids"]).split(";")}
+            assert stats["group"] == len(ids)
+            dispatched |= ids
+            continue
+        tid = stats["id"]
+        if tid in served:
+            # spans opened once the statement is parsed carry its kind
+            if name not in ("sqlcached.wire", "sqlcached.parse"):
+                assert stats["kind"] == served[tid]["kind"]
+            spans.setdefault(tid, {})[name[len("sqlcached."):]] = (a, b)
+    assert set(spans) == set(served) and set(served) <= dispatched
+    for tid, sp in spans.items():
+        assert set(TEL.STAGES) <= set(sp)
+        assert {"cut_wait", "wave_wait", "respond_wait"} <= set(sp)
+        for child, parent in TEL.CHILDREN.items():
+            if child in sp:
+                assert sp[parent][0] <= sp[child][0] <= sp[child][1] \
+                    <= sp[parent][1], (tid, child)
+        order = [sp[st] for st in TEL.STAGES]
+        assert all(x[1] <= y[0] for x, y in zip(order, order[1:]))
+
+
+def test_no_annotation_outside_a_profiler_session(server, client,
+                                                  monkeypatch):
+    """Without a session a statement only reads the clock: no
+    annotation object is built."""
+    built = []
+
+    class Counting(TEL.TraceAnnotation):
+        def __init__(self, *a, **kw):
+            built.append(a)
+            super().__init__(*a, **kw)
+
+    monkeypatch.setattr(TEL, "TraceAnnotation", Counting)
+    _traffic(client, n=8)
+    assert not TEL.Trace().prof
+    assert built == []
+
+
 def test_show_slow_log(server, client):
     server.server.db.telemetry.slow_ms = 0.0  # everything is "slow"
     _traffic(client, n=4)

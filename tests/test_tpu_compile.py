@@ -110,3 +110,46 @@ def test_sharded_table_executors_compile_for_four_chips(topo, monkeypatch):
     placements = {p for e in t.execs._entries.values() for p in e.compiled}
     assert ("mesh", (0, 1, 2, 3)) in placements
     assert {("dev", i) for i in range(4)} <= placements
+
+
+def test_executors_and_kernels_carry_their_names(topo, monkeypatch):
+    """On a profiler's ``XLA Modules`` line a program is known by its jit
+    name, and a kernel by its ``pallas_call`` name: a lane SELECT by its
+    index probe, a lane scan, and an INSERT batch lower for the v5e as
+    ``jit_sqlcached_<kind>_<path>`` with their kernels named, and the
+    INSERT's index upkeep carries the ``hashidx_upkeep`` scope. Lowered,
+    not compiled: ``ExecEntry.warm`` keeps the text."""
+    from repro.core import execache as E
+    texts: dict[str, str] = {}
+
+    def lower_only(self, placement, args):
+        if placement in self.compiled:
+            return False
+        low = self.jitted.lower(*args)
+        texts.setdefault(self.jitted.__name__, low.as_text(debug_info=True))
+        self.compiled[placement] = None
+        return True
+
+    monkeypatch.setenv("REPRO_KERNELS", "kernel")
+    monkeypatch.setattr(E.ExecEntry, "warm", lower_only)
+    mesh = jax.sharding.Mesh(np.array(topo.devices[:4]), ("lane",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
+    db = D.SQLCached(warmup=False)
+    db.execute("CREATE TABLE t (k INT, u INT, INDEX(k)) CAPACITY 65536 "
+               "MAX_SELECT 256 SHARDS 4 PARTITION BY u")
+    db.tables["t"].mesh = mesh
+    monkeypatch.setattr(D, "lane_mesh_for", lambda n, d=None: mesh)
+    for sql in ("SELECT * FROM t WHERE k = ? AND u = ?",
+                "SELECT k FROM t WHERE u = ?",
+                "INSERT INTO t (k, u) VALUES (?, ?)"):
+        assert db.execute(f"WARMUP t LIKE '{sql}'").count > 0, sql
+    want = {"sqlcached_select_probe": ["hashidx_probe"],
+            "sqlcached_select_scan": ["relscan_scan", "relscan_compact"],
+            "sqlcached_insert_batch": []}
+    assert want.keys() <= texts.keys()
+    for name, kernels in want.items():
+        assert f"module @jit_{name}" in texts[name]
+        for k in kernels:
+            assert f'kernel_name = "{k}"' in texts[name], (name, k)
+    assert "hashidx_upkeep" in texts["sqlcached_insert_batch"]
+    assert "hashidx_upkeep" not in texts["sqlcached_select_scan"]
