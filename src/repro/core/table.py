@@ -153,10 +153,14 @@ def insert(
     not supplied default to 0. ``row_mask`` ([n] bool) lets a fixed-width
     executor insert fewer than n rows (padding support). Hash-index
     maintenance for ``schema.indexes`` is fused in: batches narrower than
-    ``BULK_INDEX_THRESHOLD`` re-home all written slots in one batched
-    clear + rank-place pass (``HX.insert_update_batched`` — no serial
-    per-slot chain); wider batches take ONE bulk sort-based rebuild
-    instead. ``alloc`` pins the slot-allocator path (see ``_alloc_slots``).
+    ``BULK_INDEX_THRESHOLD`` re-home the written slots with
+    ``HX.insert_update_batched`` — each slot's old entry is cleared in
+    the one bucket of its pre-insert key (an indexed column changes only
+    here, and an UPDATE of it rebuilds the index, so the entry can live
+    nowhere else), then the batch is rank-placed in its new buckets, with
+    no serial per-slot chain; wider batches take ONE bulk sort-based
+    rebuild instead. ``alloc`` pins the slot-allocator path (see
+    ``_alloc_slots``).
 
     Returns (state, slots[n], evicted_count)."""
     payloads = payloads or {}

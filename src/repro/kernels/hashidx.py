@@ -245,10 +245,15 @@ def insert_update_batched(idx: dict, slots: jax.Array, old_keys: jax.Array,
     chain. The ``fori_loop`` above costs O(batch) *dependent* steps; this
     re-homes the whole batch in a fixed number of parallel passes:
 
-    1. **clear** — one full-array sweep drops every entry whose row id is
-       an inserted slot (the invariant says a slot lives in at most one
-       lane, so the sweep hits exactly the entries the loop's per-bucket
-       clears hit);
+    1. **clear** — each member looks for its slot in ONE bucket row, the
+       bucket of its pre-insert key (``old_keys``): the module's
+       invariant says the slot's entry can only live there. One point
+       scatter writes ``EMPTY`` at the lane found; a member with no
+       entry (a slot never indexed, or an overflow victim) scatters out
+       of range and is dropped. Members sharing an old bucket hold
+       distinct slots, and a slot holds at most one lane, so the
+       scatter's positions are distinct. The work is O(batch x
+       bucket_cap), not a pass over every index lane;
     2. **place** — batch members sharing a destination bucket get their
        within-bucket arrival rank (the ``_build_sorted`` argsort +
        searchsorted trick at batch width), and member with rank ``r``
@@ -261,23 +266,24 @@ def insert_update_batched(idx: dict, slots: jax.Array, old_keys: jax.Array,
     differ from the sequential path when one member's clear frees a lane
     an earlier member then takes — probes never read lane order, so the
     entry set is what matters (tests/test_hashidx.py compares per-bucket
-    entry sets against the loop). Its ops carry the name scope
-    ``hashidx_upkeep``."""
+    entry sets against the loop). Only in a FULL bucket does the entry
+    set differ too: a member the loop finds the bucket full for takes a
+    lane that a later member's clear frees, so the index holds more rows
+    and counts fewer stale (both sound; stale already sends probes to
+    the scan). Its ops carry the name scope ``hashidx_upkeep``."""
     nb, cap_b = idx["rid"].shape
     n = slots.shape[0]
     cap = valid.shape[0]
-    del old_keys  # the clear sweep finds entries by row id, not bucket
     act = jnp.asarray(row_mask, dtype=bool)
+    ob = bucket_of(old_keys.astype(jnp.int32), nb)
     nbk = bucket_of(new_keys.astype(jnp.int32), nb)
     validp = jnp.concatenate([valid, jnp.zeros((1,), dtype=bool)])
 
-    # 1. clear: one gather tells every lane whether it holds an inserted
-    # slot (masked rows scatter out of range and are dropped)
-    inserted = jnp.zeros((cap + 1,), dtype=bool).at[
-        jnp.where(act, slots, cap + 1)].set(True, mode="drop")
-    rid0 = idx["rid"]
-    rid0 = jnp.where((rid0 != EMPTY) & inserted[jnp.clip(rid0, 0, cap)],
-                     EMPTY, rid0)
+    # 1. clear: find each member's slot in its old bucket's row
+    held = idx["rid"][ob] == slots[:, None]       # [n, cap_b]
+    ci = jnp.where(act & jnp.any(held, axis=1), ob, nb)  # nb -> dropped
+    rid0 = idx["rid"].at[ci, jnp.argmax(held, axis=1)].set(EMPTY,
+                                                           mode="drop")
 
     # 2. place: within-bucket arrival rank -> the (rank+1)-th free lane
     b = jnp.where(act, nbk, nb)  # inactive rows sort to the sentinel end

@@ -13,7 +13,9 @@ worker imports this file. JAX's persistent compilation cache stays off
 around these compiles (an entry written without a chip cannot be read
 back)."""
 import functools
+import math
 import os
+import re
 
 import numpy as np
 import pytest
@@ -79,6 +81,35 @@ def test_hash_probe_compiles(spec):
     compiled, hlo = _compile(HX.probe, idx, idx, spec((1,), jnp.int32))
     assert "tpu_custom_call" in hlo
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def _elements(dims: str) -> int:
+    """Element count of an MLIR shape prefix such as ``4096x128x``."""
+    return math.prod(int(d) for d in dims.split("x") if d)
+
+
+@pytest.mark.parametrize("n", [1, 8])
+def test_insert_upkeep_reads_its_buckets_not_every_lane(n):
+    """The narrow-INSERT index upkeep at a 131,072-row table (4,096 x
+    128 index lanes) works on the batch's own bucket rows: no gather
+    yields one element per index lane, and no op takes or makes a mask
+    over every lane. Lowered only, so it needs no TPU."""
+    cap = 131072
+    nb = HX.n_buckets_for(cap)
+    lanes = nb * HX.BUCKET_CAP
+    sds = jax.ShapeDtypeStruct
+    table = sds((nb, HX.BUCKET_CAP), jnp.int32)
+    idx = {"rid": table, "key": table, "stale": sds((), jnp.int32)}
+    vec = sds((n,), jnp.int32)
+    text = jax.jit(HX.insert_update_batched).lower(
+        idx, vec, vec, vec, sds((n,), jnp.bool_),
+        sds((cap,), jnp.bool_)).as_text()
+    gathers = re.findall(r'"stablehlo\.gather".*-> tensor<((?:\d+x)*)\w+>',
+                         text)
+    assert gathers
+    assert max(_elements(d) for d in gathers) < lanes
+    masks = re.findall(r"tensor<((?:\d+x)+)i1>", text)
+    assert lanes not in {_elements(d) for d in masks}
 
 
 def test_vmapped_compaction_fits(spec):
